@@ -21,9 +21,9 @@ import time
 import jax
 import numpy as np
 
-from repro.compat import enable_persistent_compilation_cache
 from repro.cnn.registry import get_cnn
 from repro.core import batch_eval
+from repro.core.cache import enable_compilation_cache
 from repro.core.batch_eval import (DEFAULT_TILE, evaluate_batch,
                                    make_device_tables, make_tables,
                                    padded_rows, pes_hint)
@@ -61,7 +61,7 @@ def _peak_bytes_estimate(B: int, tables, dev) -> int:
 
 def run(verbose: bool = True, quick: bool = False,
         sizes=None) -> dict:
-    enable_persistent_compilation_cache()
+    enable_compilation_cache()
     backend = resolve_backend(None)
     net, dev = get_cnn("xception"), get_board("vcu110")
     tables = make_tables(net)
@@ -330,34 +330,22 @@ def run(verbose: bool = True, quick: bool = False,
                   f"{max(first_s - schsteady, 0.0):.2f}",
                   f"x{schsteady / ssteady:.1f} eval"])
 
-    # ---- sharded weak-scaling: one subprocess per forced host-device
-    # count (the backend pins its device count at init, so every point
-    # needs a fresh interpreter; benchmarks.sharded_eval exports
-    # REPRO_MESH_DEVICES before its first jax import)
-    import json as _json
-    import subprocess
-    import sys
+    # ---- sharded weak-scaling.  On the CPU: one subprocess per forced
+    # host-device count (the backend pins its device count at init).  On
+    # an accelerator a chip belongs to one process, so this process
+    # measures one device and every visible device itself
+    from . import serve_load, sharded_eval
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep \
-        + env.get("PYTHONPATH", "")
     cores = os.cpu_count() or 1
-    dev_counts = (1, 2) if quick else (1, 2, 4, 8)
-    per_dev, recompiles = {}, 0
-    for n in dev_counts:
-        env["REPRO_MESH_DEVICES"] = str(n)
-        cmd = [sys.executable, "-m", "benchmarks.sharded_eval",
-               "--devices", str(n), "--json"]
-        if quick:
-            cmd.append("--quick")
-        out = subprocess.run(cmd, env=env, cwd=root, capture_output=True,
-                             text=True, timeout=1800)
-        if out.returncode != 0:
-            raise RuntimeError(f"sharded_eval --devices {n} failed:\n"
-                               f"{out.stdout}\n{out.stderr}")
-        p = _json.loads(out.stdout.strip().splitlines()[-1])
-        per_dev[str(n)] = p
+    if jax.default_backend() == "cpu":
+        dev_counts = (1, 2) if quick else (1, 2, 4, 8)
+        per_dev = {str(n): _forced_host_point(n, quick) for n in dev_counts}
+    else:
+        dev_counts = sorted({1, len(jax.devices())})
+        per_dev = {str(n): sharded_eval.run(n, quick=quick, verbose=False)
+                   for n in dev_counts}
+    recompiles = 0
+    for n, p in per_dev.items():
         recompiles += p["eval"]["recompiles_on_tail_reeval"]
         table.append([f"sharded n={n} B={p['eval']['B']}",
                       f"{p['eval']['us_per_design']:.1f}", "-",
@@ -385,20 +373,9 @@ def run(verbose: bool = True, quick: bool = False,
     }
 
     # ---- serving front: mixed CNN x board traffic over the socket
-    # service, with a background DSE job on the batch lane.  Subprocess
-    # for isolation: the load generator owns its Session/server and must
-    # not inherit this process's warmed default session
-    env.pop("REPRO_MESH_DEVICES", None)   # left over from the scan above
-    cmd = [sys.executable, "-m", "benchmarks.serve_load", "--json",
-           "--seed", "0"]
-    if quick:
-        cmd.append("--quick")
-    out = subprocess.run(cmd, env=env, cwd=root, capture_output=True,
-                         text=True, timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(f"serve_load failed:\n{out.stdout}\n"
-                           f"{out.stderr}")
-    sv = _json.loads(out.stdout.strip())
+    # service, with a background DSE job on the batch lane.  The load
+    # generator builds its own Session and server in this process
+    sv = serve_load.run(seed=0, quick=quick, verbose=False)
     points["serve_load"] = sv
     table.append([f"serve n={sv['n_requests']}",
                   "-", "-", f"{sv['designs_per_s']:.0f}/s",
@@ -470,6 +447,31 @@ def run(verbose: bool = True, quick: bool = False,
         print("checks:", payload["checks"])
     save("BENCH_eval", payload)
     return payload
+
+
+def _forced_host_point(n: int, quick: bool) -> dict:
+    """``benchmarks.sharded_eval`` at ``n`` forced host devices, in a
+    fresh interpreter (it exports REPRO_MESH_DEVICES before importing
+    jax).  CPU only: on a chip host the child could not get the chip."""
+    import json
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep \
+        + env.get("PYTHONPATH", "")
+    env["REPRO_MESH_DEVICES"] = str(n)
+    cmd = [sys.executable, "-m", "benchmarks.sharded_eval",
+           "--devices", str(n), "--json"]
+    if quick:
+        cmd.append("--quick")
+    out = subprocess.run(cmd, env=env, cwd=root, capture_output=True,
+                         text=True, timeout=1800)
+    if out.returncode != 0:
+        raise RuntimeError(f"sharded_eval --devices {n} failed:\n"
+                           f"{out.stdout}\n{out.stderr}")
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def main(argv=None) -> int:

@@ -18,12 +18,6 @@ def main(argv=None) -> int:
                     help="comma-separated benchmark names")
     args = ap.parse_args(argv)
 
-    from repro.compat import enable_persistent_compilation_cache
-
-    # opt-in on-disk jit cache (REPRO_JAX_CACHE_DIR=...): repeated harness
-    # runs skip every compile — see docs/perf.md
-    enable_persistent_compilation_cache()
-
     from . import (eval_speed, fig5_fig8_fronts, fig6_fig7_breakdown,
                    fig9_fig10_dse, multinet_fronts, multinet_hybrid,
                    perf_gate,

@@ -10,9 +10,11 @@ the island-model DSE against their single-device equivalents:
 Device count must be fixed before jax initialises its backend, so
 ``main`` exports ``REPRO_MESH_DEVICES`` *first* and only then imports the
 repro stack — the same single env-var path users follow (docs/perf.md).
-That also means one process measures exactly one device count;
-``perf_gate`` spawns this module as a subprocess per point to build the
-weak-scaling curve.
+That also means one process measures exactly one forced host-device
+count; on the CPU ``perf_gate`` spawns this module as a subprocess per
+point to build the weak-scaling curve.  On an accelerator, where a chip
+belongs to one process, ``perf_gate`` calls :func:`run` in its own
+process at one device and at every visible device.
 
 On CPU, forced host devices are real XLA devices scheduled across cores:
 aggregate designs/sec scales with ``min(ndevices, physical cores)`` and
@@ -46,17 +48,17 @@ def run(ndevices: int, *, b: int | None = None, quick: bool = False,
     import jax
     import numpy as np
 
-    from repro.compat import enable_persistent_compilation_cache
     from repro.cnn.registry import get_cnn
     from repro.core.batch_eval import evaluate_batch, make_tables
+    from repro.core.cache import enable_compilation_cache
     from repro.core.dse.samplers import sample_mixed
     from repro.core.dse.search import SearchConfig, search
     from repro.core.shard import EvalMesh, mesh_compile_counts
     from repro.fpga.boards import get_board
 
-    enable_persistent_compilation_cache()
+    enable_compilation_cache()
     B = b or (EVAL_B_QUICK if quick else EVAL_B_FULL)
-    mesh = EvalMesh()
+    mesh = EvalMesh(ndevices)
     got = mesh.ndevices
     if got != ndevices and verbose:
         print(f"# requested {ndevices} devices, backend exposes {got}",

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.mccm_eval import pair_tables, parallelism_search, resolve_backend
+from ..kernels.mccm_eval.ref import EXACT
 from .blocks import CANDIDATES_DEFAULT
 from .device import DeviceSpec
 from .dse.encoding import NC, NS, DesignBatch, encode_specs  # noqa: F401
@@ -46,6 +47,13 @@ from .notation import AcceleratorSpec
 from .workload import Network
 
 NEG = -1.0e30
+
+#: relative tolerance of batch (f32) vs scalar-evaluator (f64) agreement,
+#: per metric — what the parity tests and ``chip_smoke.py`` hold the
+#: evaluator to.  Off-chip access may flip Eq. 6's option at f32
+#: thresholds, hence its wider bound.
+SCALAR_PARITY_RTOL = {"latency_s": 1e-4, "throughput_ips": 1e-4,
+                      "buffer_bytes": 1e-4, "access_bytes": 0.04}
 
 #: base of the layer-axis padding ladder: covers the whole CNN zoo
 #: (resnet152 = 155), so one compiled program serves every registered CNN.
@@ -264,7 +272,7 @@ def _seg_onehot(seg_of_layer, valid_layer):
 
 def _seg_sum(x, onehot):
     """sum of per-layer x (B, L) into segments -> (B, NS)."""
-    return jnp.einsum("bl,bls->bs", x, onehot)
+    return jnp.einsum("bl,bls->bs", x, onehot, precision=EXACT)
 
 
 def _seg_max(x, onehot):
@@ -352,7 +360,8 @@ def _ce_maps(design: DesignBatch, t: NetTables, dev: DeviceTables) -> _CEMaps:
     ce_of_layer = jnp.clip(ce_of_layer, 0, NC - 1)
 
     # PE distribution (largest remainder over per-CE MACs)
-    macs_ce = jnp.einsum("l,blc->bc", jnp.asarray(t.MACS), ce_oh)
+    macs_ce = jnp.einsum("l,blc->bc", jnp.asarray(t.MACS), ce_oh,
+                         precision=EXACT)
     ce_valid = jnp.einsum("blc->bc", ce_oh) > 0
     pes_ce = _largest_remainder(macs_ce, dev.pes, ce_valid)
     return _CEMaps(seg_start, seg_len, seg_valid, n_seg, seg_of_layer,
@@ -434,9 +443,12 @@ def layer_state(design: DesignBatch, t: NetTables, dev: DeviceTables,
     # ---- per-layer compute cycles & utilization --------------------------
     macs = jnp.asarray(t.MACS)
     ckk = jnp.asarray(t.CKK)
-    pf_l = jnp.where(valid_b, jnp.einsum("bc,blc->bl", pf_ce, ce_oh), 1.0)
-    ph_l = jnp.where(valid_b, jnp.einsum("bc,blc->bl", ph_ce, ce_oh), 1.0)
-    pw_l = jnp.where(valid_b, jnp.einsum("bc,blc->bl", pw_ce, ce_oh), 1.0)
+    pf_l = jnp.where(valid_b, jnp.einsum("bc,blc->bl", pf_ce, ce_oh,
+                                         precision=EXACT), 1.0)
+    ph_l = jnp.where(valid_b, jnp.einsum("bc,blc->bl", ph_ce, ce_oh,
+                                         precision=EXACT), 1.0)
+    pw_l = jnp.where(valid_b, jnp.einsum("bc,blc->bl", pw_ce, ce_oh,
+                                         precision=EXACT), 1.0)
     F = jnp.asarray(t.F)
     OH = jnp.asarray(t.OH)
     OW = jnp.asarray(t.OW)
@@ -509,19 +521,24 @@ def layer_state(design: DesignBatch, t: NetTables, dev: DeviceTables,
     gaps = jnp.maximum(desires - alloc, 0.0)
     gap_sum = gaps.sum(-1)
     grant = jnp.minimum(jnp.maximum(remaining, 0.0), gap_sum)
-    alloc = alloc + jnp.where(gap_sum[:, None] > 0,
-                              jnp.floor(grant[:, None] * gaps
-                                        / jnp.maximum(gap_sum[:, None], 1.0)),
-                              0.0)
+    # a grant that covers every gap fills each exactly: on a TPU the f32
+    # division is not correctly rounded, and floor(g * G / G) can land on
+    # g - 1, one byte short of the weight-resident regime (Eq. 5)
+    share = jnp.where(grant[:, None] >= gap_sum[:, None], gaps,
+                      jnp.floor(grant[:, None] * gaps
+                                / jnp.maximum(gap_sum[:, None], 1.0)))
+    alloc = alloc + jnp.where(gap_sum[:, None] > 0, share, 0.0)
 
     # ---- pipelined per-CE buffer split (desire share within segment) ------
     ce_desire_l = (W[None] * wb + fm_tile2) * pipe_l     # (B, L)
-    ce_desire = jnp.einsum("bl,blc->bc", ce_desire_l, ce_oh)
+    ce_desire = jnp.einsum("bl,blc->bc", ce_desire_l, ce_oh,
+                           precision=EXACT)
     seg_of_ce_desire = _seg_sum(ce_desire_l, onehot)     # (B, NS)
     alloc_of_layer = jnp.take_along_axis(alloc, seg_of_layer, axis=1)
     segdes_of_layer = jnp.take_along_axis(
         jnp.maximum(seg_of_ce_desire, 1.0), seg_of_layer, axis=1)
-    cedes_of_layer = jnp.einsum("bc,blc->bl", ce_desire, ce_oh)
+    cedes_of_layer = jnp.einsum("bc,blc->bl", ce_desire, ce_oh,
+                                precision=EXACT)
     ce_buf_of_layer = jnp.floor(
         alloc_of_layer * cedes_of_layer / segdes_of_layer)
 
@@ -671,7 +688,8 @@ def compose_metrics(design: DesignBatch, t: NetTables, dev: DeviceTables,
 
     # per-CE busy (Eq. 3 / throughput)
     busy_l = st.busy_pipe                                # pipelined layers
-    busy_slot = jnp.einsum("bl,blc->bc", busy_l * pipe_l, ce_oh)  # (B, NC)
+    busy_slot = jnp.einsum("bl,blc->bc", busy_l * pipe_l, ce_oh,
+                           precision=EXACT)                   # (B, NC)
     # pipelined block busy = max over its slots; map back per segment:
     seg_of_ce = jnp.sum(
         (jnp.arange(NC)[None, :, None]

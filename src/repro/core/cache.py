@@ -1,4 +1,5 @@
-"""Bounded LRU caches for the long-lived serving path.
+"""Bounded LRU caches for the long-lived serving path, and the placement
+of JAX's persistent compilation cache.
 
 A :class:`Session` memoizes ``NetTables``/``DeviceTables``/
 ``MultiNetTables`` and the mesh's sharded jits.  In a notebook those
@@ -14,11 +15,17 @@ Thread safety is the *owner's* job — the Session holds its table lock
 across get+put, exactly as it did over the plain dicts.  Bounds resolve
 from the environment once, at session construction:
 ``REPRO_CACHE_TABLES`` / ``REPRO_CACHE_JITS`` (``docs/serving.md``).
+
+Compiled programs persist on disk through JAX's own compilation cache
+(:func:`enable_compilation_cache`, called by every ``Session``): where
+``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it and that directory is
+used as it is; otherwise the cache lives at ``<checkout>/.jax_cache``.
 """
 from __future__ import annotations
 
 import os
 from collections import OrderedDict
+from pathlib import Path
 from typing import Callable
 
 #: env knobs (read once, in ``EvalConfig.resolved()``)
@@ -29,6 +36,28 @@ JITS_ENV = "REPRO_CACHE_JITS"
 #: sessions and the test suite never evict, small enough to bound memory
 DEFAULT_MAX_TABLES = 256
 DEFAULT_MAX_JITS = 128
+
+
+#: where compiled programs persist when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset.  A fixed path: the directory is part of what a later process
+#: looks entries up by, so a moving one would never hit.
+DEFAULT_COMPILE_CACHE = str(Path(__file__).resolve().parents[3]
+                            / ".jax_cache")
+
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  A directory JAX already has (from
+    ``JAX_COMPILATION_CACHE_DIR`` or an earlier ``jax.config`` update) is
+    kept as it is; only when there is none is ``DEFAULT_COMPILE_CACHE``
+    set.  Idempotent."""
+    import jax
+
+    path = jax.config.jax_compilation_cache_dir
+    if not path:
+        path = DEFAULT_COMPILE_CACHE
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def env_bound(env: str, default: int) -> int:

@@ -90,10 +90,7 @@ def _explore(net, dev, n: int = 100_000, *,
 
     import jax
 
-    from ...compat import enable_persistent_compilation_cache
     from ..batch_eval import _pad_rows, evaluate_batch, make_tables
-
-    enable_persistent_compilation_cache()
 
     def sampler(rng, n_layers, b):
         if family == "custom":

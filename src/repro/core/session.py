@@ -13,9 +13,10 @@ is constructed once per process and owns all of it:
   ``DeviceTables`` keyed by board, ``MultiNetTables`` keyed by the model
   set + weights/SLOs, so the one-compile-serves-all property of
   ``batch_eval``/``joint_eval`` is automatic instead of opt-in;
-* **one config** — :class:`EvalConfig` resolves the kernel backend and the
-  persistent-compile-cache dir ONCE at session creation; every downstream
-  call inherits it (no scattered env reads);
+* **one config** — :class:`EvalConfig` resolves the kernel backend ONCE at
+  session creation; every downstream call inherits it (no scattered env
+  reads), and JAX's persistent compilation cache is switched on there
+  (``core.cache.enable_compilation_cache``);
 * **one surface** — :meth:`Session.evaluate` (scalar spec, spec list or
   ``DesignBatch``, dispatching on input), :meth:`Session.explore` (DSE),
   :meth:`Session.deploy` (multinet), and :meth:`Session.submit` → Future
@@ -35,14 +36,14 @@ from dataclasses import dataclass, fields, replace
 
 import numpy as np
 
-from ..compat import enable_persistent_compilation_cache
 from ..kernels.mccm_eval import resolve_backend
 from . import telemetry
 from .batch_eval import (DEFAULT_TILE, DeviceTables, NetTables,
                          _evaluate_specs, _evaluate_specs_multi,
                          bucket_max_L, evaluate_batch, make_device_tables,
                          make_tables)
-from .cache import (DEFAULT_MAX_TABLES, TABLES_ENV, BoundedLRU, env_bound)
+from .cache import (DEFAULT_MAX_TABLES, TABLES_ENV, BoundedLRU,
+                    enable_compilation_cache, env_bound)
 from .coalesce import ArrivalEstimator, plan_megabatch
 from .device import DeviceSpec
 from .dse.driver import DEFAULT_OBJECTIVES
@@ -62,9 +63,9 @@ class EvalConfig:
     """Every evaluation knob in one place, resolved at session creation.
 
     ``backend=None`` reads ``REPRO_MCCM_BACKEND`` (falling back to auto:
-    pallas on TPU, ref elsewhere) and pins the result; ``cache_dir=None``
-    reads ``REPRO_JAX_CACHE_DIR``.  Both env vars are consulted exactly
-    once — at :class:`Session` construction — instead of per call.
+    pallas on TPU, ref elsewhere) and pins the result.  The env var is
+    consulted exactly once — at :class:`Session` construction — instead of
+    per call.
     """
 
     #: parallelism-search kernel backend ("ref" | "pallas" |
@@ -84,8 +85,6 @@ class EvalConfig:
     #: model-axis padding of deploy()'s MultiNetTables; None = the
     #: multinet default (DEFAULT_MAX_M)
     max_m: int | None = None
-    #: persistent jit-cache directory; None resolves REPRO_JAX_CACHE_DIR
-    cache_dir: str | None = None
     #: submit() megabatching window: how long the drain loop lingers after
     #: the first queued request before evaluating, so concurrent callers
     #: land in one compiled dispatch
@@ -133,11 +132,8 @@ class EvalConfig:
     max_cached_jits: int | None = None
 
     def resolved(self) -> "EvalConfig":
-        """Pin the env-dependent fields (backend, cache_dir, mesh, cache
-        bounds) to concrete values — called once by :class:`Session`."""
-        import os
-
-        from ..compat import CACHE_ENV
+        """Pin the env-dependent fields (backend, mesh, cache bounds) to
+        concrete values — called once by :class:`Session`."""
         from .shard import env_mesh_devices
         if self.max_retries < 0:
             raise ValueError(f"max_retries must be >= 0, "
@@ -154,7 +150,6 @@ class EvalConfig:
             backend=resolve_backend(self.backend),
             fallback_backend=None if self.fallback_backend is None
             else resolve_backend(self.fallback_backend),
-            cache_dir=self.cache_dir or os.environ.get(CACHE_ENV) or None,
             mesh=self.mesh if self.mesh is not None else env_mesh_devices(),
             max_cached_tables=env_bound(TABLES_ENV, DEFAULT_MAX_TABLES)
             if self.max_cached_tables is None else self.max_cached_tables,
@@ -284,8 +279,7 @@ class Session:
         if overrides:
             base = replace(base, **overrides)
         self.config = base.resolved()
-        if self.config.cache_dir:
-            enable_persistent_compilation_cache(self.config.cache_dir)
+        enable_compilation_cache()
         from .shard import EvalMesh
         #: the session's design-axis mesh; single-device meshes delegate
         #: to the exact single-device jits (zero extra compiles)

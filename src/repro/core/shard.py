@@ -61,7 +61,6 @@ if _env_n.isdigit():
 import jax                                           # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P    # noqa: E402
 
-from ..compat import shard_map                       # noqa: E402
 from . import telemetry                              # noqa: E402
 from .batch_eval import (                            # noqa: E402
     DEFAULT_TILE, _pad_rows, evaluate_batch_traced, padded_rows)
@@ -183,8 +182,13 @@ class EvalMesh:
         def run(*args):
             specs = tuple(P() if i in repl else P(MESH_AXIS)
                           for i in range(len(args)))
-            return shard_map(body, mesh=mesh, in_specs=specs,
-                             out_specs=P(MESH_AXIS))(*args)
+            # check_vma=False: every row is computed on its own shard and
+            # no collective runs, so the varying-axes check has nothing to
+            # guard — and it rejects the Pallas kernel, whose out_shape
+            # carries no vma, in compiled and interpret mode alike
+            return jax.shard_map(body, mesh=mesh, in_specs=specs,
+                                 out_specs=P(MESH_AXIS),
+                                 check_vma=False)(*args)
 
         jitted = jax.jit(run, donate_argnums=donate_argnums)
         _REGISTRY.append((name, jitted))

@@ -41,11 +41,8 @@ def _conv_kernel(x_ref, w_ref, o_ref, *, stride: int, par_f: int,
 
     def oh_body(dh, acc):
         def ow_body(dw, acc):
-            patch = pl.load(
-                x_ref,
-                (slice(None),
-                 pl.dslice((oh0 + dh) * stride, KH),
-                 pl.dslice((ow0 + dw) * stride, KW))).astype(jnp.float32)
+            patch = x_ref[:, pl.ds((oh0 + dh) * stride, KH),
+                          pl.ds((ow0 + dw) * stride, KW)].astype(jnp.float32)
             col = patch.reshape(C * KH * KW)
             val = wf @ col                            # (par_f,) — MXU row
             return acc.at[:, dh, dw].set(val)

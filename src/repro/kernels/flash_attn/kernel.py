@@ -32,20 +32,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *,
     Sk_pad = k_ref.shape[1]
     nk = Sk_pad // kv_blk
     D = q_ref.shape[2]
-    # NOTE: every indexer below is an explicit Slice — plain int indices
-    # break jax 0.4.x interpret-mode state discharge (_load_discharge_rule
-    # assumes indexers carry .shape)
-    q = pl.load(q_ref, (pl.dslice(0, 1), pl.dslice(0, q_blk),
-                        pl.dslice(0, D)))[0].astype(jnp.float32) * scale
+    q = q_ref[0].astype(jnp.float32) * scale
 
     q_abs = qi * q_blk + jax.lax.broadcasted_iota(jnp.int32, (q_blk, 1), 0)
 
     def body(kj, carry):
         acc, m, l = carry
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(kj * kv_blk, kv_blk),
-                            pl.dslice(0, D)))[0].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(kj * kv_blk, kv_blk),
-                            pl.dslice(0, D)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(kj * kv_blk, kv_blk), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(kj * kv_blk, kv_blk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         k_abs = kj * kv_blk + jax.lax.broadcasted_iota(
@@ -71,8 +65,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *,
     l0 = jnp.zeros((q_blk, 1), jnp.float32)
     acc, m, l = jax.lax.fori_loop(0, nk, body, (acc0, m0, l0))
     l = jnp.where(l == 0.0, 1.0, l)
-    pl.store(o_ref, (pl.dslice(0, 1), pl.dslice(0, q_blk), pl.dslice(0, D)),
-             (acc / l).astype(o_ref.dtype)[None])
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 def flash_fwd(q, k, v, *, causal: bool = True, window: int | None = None,

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .ref import EXACT
+
 
 # --------------------------------------------------------------------------
 # Eq. 1 latency sweep (kept from the original toy kernel)
@@ -95,18 +97,21 @@ def _search_kernel(ncand: int):
 
         # per-layer pw of the layer's CE: one-hot contraction (MXU)
         pw_l = jax.lax.dot_general(
-            ce_oh, pwv, (((2,), (1,)), ((0,), (0,))),
+            ce_oh, pwv, (((2,), (1,)), ((0,), (0,))), precision=EXACT,
             preferred_element_type=jnp.float32)          # (T, L, P)
         cow = jnp.ceil(ow_ref[...][None] / jnp.maximum(pw_l, 1.0))
         cost_l = fc_ref[...][None] * coh_ref[...][None] * cow   # (T, L, P)
         cost_ce = jax.lax.dot_general(
-            ce_oh, cost_l, (((1,), (1,)), ((0,), (0,))),
+            ce_oh, cost_l, (((1,), (1,)), ((0,), (0,))), precision=EXACT,
             preferred_element_type=jnp.float32)          # (T, NC, P)
         cost_ce = jnp.where(feasible, cost_ce, jnp.inf)
 
-        best = jnp.argmin(cost_ce, axis=-1)              # (T, NC)
-        sel = best[..., None] == jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, P), 2)                     # (T, NC, P)
+        # first minimum, spelled out: Mosaic's argmin does not return the
+        # first of tied minima, and ties must resolve as the ref's argmin
+        iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, P), 2)
+        low = cost_ce.min(-1, keepdims=True)
+        best = jnp.where(cost_ce == low, iota, P).min(-1)  # (T, NC)
+        sel = best[..., None] == iota                    # (T, NC, P)
         self_f = sel.astype(jnp.float32)
         pf_out[...] = (pfv_ref[...][0][None, None, :] * self_f).sum(-1)
         ph_out[...] = (phv_ref[...][0][None, None, :] * self_f).sum(-1)

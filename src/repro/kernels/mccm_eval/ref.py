@@ -19,7 +19,13 @@ device total), so selection is identical to an argmin over the full grid.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+#: precision of the one-hot contractions.  On a TPU an f32 contraction at
+#: default precision rounds its operands to bf16 (~0.2% off cycle counts
+#: and MACs); HIGHEST keeps them f32 (exact against one-hot operands)
+EXACT = jax.lax.Precision.HIGHEST
 
 
 def mccm_latency_ref(dims, par):
@@ -69,7 +75,8 @@ def parallelism_search_ref(pes_ce, ce_of_layer, ce_oh,
     pw_sel = jnp.take_along_axis(pw_idx, ce_of_layer[:, :, None], axis=1)
     cow = ceil_ow[jnp.arange(L)[None, :, None], pw_sel]         # (B, L, P)
     cost_l = fc_pair[None] * coh_pair[None] * cow               # (B, L, P)
-    cost_ce = jnp.einsum("blp,blc->bcp", cost_l, ce_oh)         # (B, NC, P)
+    cost_ce = jnp.einsum("blp,blc->bcp", cost_l, ce_oh,
+                         precision=EXACT)                       # (B, NC, P)
     cost_ce = jnp.where(feasible, cost_ce, jnp.inf)
     best = jnp.argmin(cost_ce, axis=-1)                         # (B, NC)
     pf = pair_pf[best]

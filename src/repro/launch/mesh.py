@@ -12,27 +12,16 @@ manually still works but is subsumed by the env var.)
 from __future__ import annotations
 
 import jax
-
-try:                                # jax >= 0.5 explicit-sharding API
-    from jax.sharding import AxisType
-except ImportError:                 # older jax: meshes are Auto by default
-    AxisType = None
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
 def _mesh(shape, axes, devices=None):
-    if AxisType is None:
-        if devices is not None:
-            import numpy as np
-            from jax.sharding import Mesh
-            return Mesh(np.asarray(devices).reshape(shape), axes)
-        return jax.make_mesh(shape, axes)
+    types = (AxisType.Auto,) * len(axes)
     if devices is not None:
-        import numpy as np
-        from jax.sharding import Mesh
         return Mesh(np.asarray(devices).reshape(shape), axes,
-                    axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+                    axis_types=types)
+    return jax.make_mesh(shape, axes, axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -31,7 +31,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-from ..compat import shard_map
 
 from ..configs import SHAPES, get_config
 from ..configs.base import ShapeSpec
@@ -117,7 +116,7 @@ def main(argv=None) -> int:
             from jax.sharding import PartitionSpec as P
             dp_axis = plan.dp_axes[0] if plan.dp_axes else "data"
             jit_step = jax.jit(
-                shard_map(
+                jax.shard_map(
                     step, mesh=mesh,
                     in_specs=(P(), P(), P(dp_axis)),
                     out_specs=(P(), P(), P()),

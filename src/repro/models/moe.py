@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from ..compat import shard_map
 
 from .layers import _dense_init
 
@@ -144,7 +143,7 @@ def moe_ep(params: Params, x, cfg, mesh, *, ep_axis: str, dp_axes: tuple[str, ..
         return y.reshape(B, S, D), aux
 
     xs = P(*([dp_axes] + [None] * (x.ndim - 1)))
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(xs, P(), P(ep_axis), P(ep_axis), P(ep_axis)),
@@ -213,7 +212,7 @@ def moe_ep_a2a(params: Params, x, cfg, mesh, *, ep_axis: str,
     # tokens sharded over dp axes (batch) AND the EP axis (sequence): each
     # shard routes only its own S/ep slice, then a2a moves expert blocks.
     xs = P(dp_axes, ep_axis, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(xs, P(), P(ep_axis), P(ep_axis), P(ep_axis)),

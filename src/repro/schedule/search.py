@@ -36,6 +36,7 @@ from ..core.batch_eval import (DEFAULT_TILE, NEG, DesignBatch, DeviceSpec,
 from ..core.dse.encoding import NS, encode_specs
 from ..kernels.mccm_eval import pair_tables, parallelism_search
 from ..kernels.mccm_eval import resolve_backend as resolve_eval_backend
+from ..kernels.mccm_eval.ref import EXACT
 from ..kernels.schedule_score import NCAND
 from ..kernels.schedule_score.ops import score_plane_dispatch
 
@@ -141,9 +142,9 @@ def schedule_block(design: DesignBatch, t: NetTables, dev: DeviceTables,
         budget_bytes=take(plane["budget_bytes"]),
         lat_ref_l=lat_ref_l, lat_coarse_l=lat_coarse_l,
         acc_ref_l=acc_ref_l, acc_coarse_l=acc_coarse_l,
-        pf_l=jnp.einsum("bc,blc->bl", pf, m.ce_oh),
-        ph_l=jnp.einsum("bc,blc->bl", ph, m.ce_oh),
-        pw_l=jnp.einsum("bc,blc->bl", pw, m.ce_oh),
+        pf_l=jnp.einsum("bc,blc->bl", pf, m.ce_oh, precision=EXACT),
+        ph_l=jnp.einsum("bc,blc->bl", ph, m.ce_oh, precision=EXACT),
+        pw_l=jnp.einsum("bc,blc->bl", pw, m.ce_oh, precision=EXACT),
         ce_of_layer=m.ce_of_layer, seg_of_layer=m.seg_of_layer,
         pipe_l=pipe,
         valid_l=jnp.broadcast_to(valid, (design.batch, t.max_L)),

@@ -18,7 +18,7 @@ Usage:
     python tests/chaos_kill_resume.py worker <mode> <ckpt|-> <out.npz>
 
 Workers honour ``REPRO_CHAOS_KILL_AFTER=N`` (die after the N-th snapshot)
-and share one ``REPRO_JAX_CACHE_DIR`` so the three runs per mode compile
+and share one ``JAX_COMPILATION_CACHE_DIR`` so the three runs per mode compile
 once.  Run by the ``chaos-smoke`` CI job and
 ``tests/test_chaos.py::test_sigkill_and_resume_subprocess``.
 """
@@ -96,7 +96,7 @@ def _spawn(mode: str, ckpt: str, out: str, *, kill_after: int = 0,
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env["REPRO_MCCM_BACKEND"] = "ref"
-    env["REPRO_JAX_CACHE_DIR"] = cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env["REPRO_MESH_DEVICES"] = \
         str(ISLAND_DEVICES) if mode == "island" else "1"
     env.pop("REPRO_CHAOS_KILL_AFTER", None)

@@ -4,7 +4,8 @@ The parent sets ``REPRO_MESH_DEVICES`` (NOT ``XLA_FLAGS``) so this also
 exercises the supported env-var path: importing ``repro.core.shard``
 before first jax use must force-split the host platform by itself.
 
-Usage: python tests/shard_worker.py <job> — jobs: parity | islands | cache.
+Usage: python tests/shard_worker.py <job> — jobs: parity | islands | cache |
+pallas.
 Prints ``WORKER_OK <job>`` on success; any assertion failure exits nonzero.
 """
 from __future__ import annotations
@@ -59,6 +60,33 @@ def job_parity():
     assert be._evaluate_jit._cache_size() == 1
     print(f"WORKER_OK parity ({len(CNN_NAMES)} CNNs x {len(ARCH_NAMES)} "
           f"archs, {WANT} devices)")
+
+
+def job_pallas():
+    """The sharded Pallas kernel (interpret mode) equals the one-device
+    program bit for bit, called directly and through a Session with no
+    fallback — the path shard_map's varying-axes check used to reject."""
+    from repro.core.dse.encoding import decode_design
+    from repro.core.dse.samplers import sample_mixed
+
+    net = get_cnn("resnet50")
+    dev = get_board("zc706")
+    mesh = EvalMesh()
+    assert mesh.is_sharded and mesh.ndevices == WANT
+    db = sample_mixed(np.random.default_rng(0), len(net), 64)
+    tables = be.make_tables(net)
+    kw = dict(tile=TILE, backend="pallas_interpret")
+    single = be.evaluate_batch(db, tables, dev, **kw)
+    sharded = be.evaluate_batch(db, tables, dev, mesh=mesh, **kw)
+    for k in single:
+        _eq(single[k], sharded[k], f"pallas {k} diverges sharded")
+    specs = [decode_design(db, i, len(net)) for i in range(db.batch)]
+    cfg = dict(tile=TILE, backend="pallas_interpret", fallback_backend=None)
+    got = Session(dev, **cfg).evaluate(specs, net)
+    want = Session(dev, mesh=1, **cfg).evaluate(specs, net)
+    for k in want:
+        _eq(got[k], want[k], f"session pallas {k} diverges sharded")
+    print(f"WORKER_OK pallas ({WANT} devices, {db.batch} designs)")
 
 
 def job_islands():
@@ -122,4 +150,4 @@ def job_cache():
 
 if __name__ == "__main__":
     {"parity": job_parity, "islands": job_islands,
-     "cache": job_cache}[sys.argv[1]]()
+     "cache": job_cache, "pallas": job_pallas}[sys.argv[1]]()

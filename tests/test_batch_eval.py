@@ -5,15 +5,15 @@ import numpy as np
 import pytest
 
 from repro.cnn.registry import CNN_NAMES, get_cnn
-from repro.core.batch_eval import encode_specs, evaluate_specs, make_tables
+from repro.core.batch_eval import (SCALAR_PARITY_RTOL, encode_specs,
+                                   evaluate_specs, make_tables)
 from repro.core.dse import decode_design, explore, pareto, sample_mixed
 from repro.core.evaluator import evaluate_design
 from repro.fpga.archs import ARCH_NAMES, make_arch
 from repro.fpga.boards import get_board
 
-METRICS = ("latency_s", "throughput_ips", "buffer_bytes", "access_bytes")
-RTOL = {"latency_s": 1e-4, "throughput_ips": 1e-4,
-        "buffer_bytes": 1e-4, "access_bytes": 0.04}  # f32 threshold flips
+METRICS = tuple(SCALAR_PARITY_RTOL)
+RTOL = SCALAR_PARITY_RTOL
 
 
 def _scalar_vals(m):

@@ -239,3 +239,8 @@ def test_worker_island_sharded_equals_serial():
 @pytest.mark.slow
 def test_worker_session_cache_stability():
     _run_worker("cache")
+
+
+@pytest.mark.slow
+def test_worker_sharded_pallas_interpret_equals_one_device():
+    _run_worker("pallas")

@@ -11,7 +11,6 @@ import pytest
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs import get_config
 from repro.configs.base import ShapeSpec
 from repro.data.pipeline import synth_batch
@@ -123,7 +122,7 @@ def test_compressed_ddp_close_to_fp32(host_mesh):
     plain = jax.jit(make_train_step(api, rt, opt))
     comp_raw = make_compressed_train_step(api, rt, opt, axis="data",
                                           n_shards=host_mesh.shape["data"])
-    comp = jax.jit(shard_map(
+    comp = jax.jit(jax.shard_map(
         comp_raw, mesh=host_mesh,
         in_specs=(P(), P(), P("data")),
         out_specs=(P(), P(), P()), check_vma=False))
@@ -149,7 +148,7 @@ def test_compressed_wire_bytes_4x_smaller(host_mesh):
     cfg, api, opt, state = _setup(rt=rt)
     comp_raw = make_compressed_train_step(api, rt, opt, axis="data",
                                           n_shards=host_mesh.shape["data"])
-    comp = jax.jit(shard_map(
+    comp = jax.jit(jax.shard_map(
         comp_raw, mesh=host_mesh,
         in_specs=(P(), P(), P("data")),
         out_specs=(P(), P(), P()), check_vma=False))
