@@ -760,8 +760,10 @@ def compose_metrics(design: DesignBatch, t: NetTables, dev: DeviceTables,
 def _evaluate_core(design: DesignBatch, t: NetTables, dev: DeviceTables,
                    m: _CEMaps, par, fm_tile_rows: int) -> dict:
     """Full MCCM evaluation: per-layer state then Eq. 2–9 composition."""
-    return compose_metrics(design, t, dev, m,
-                           layer_state(design, t, dev, m, par, fm_tile_rows))
+    with jax.named_scope("layer_state"):
+        st = layer_state(design, t, dev, m, par, fm_tile_rows)
+    with jax.named_scope("compose_metrics"):
+        return compose_metrics(design, t, dev, m, st)
 
 
 def _pad_rows(design: DesignBatch, n: int) -> DesignBatch:
@@ -794,12 +796,16 @@ def eval_design_block(design: DesignBatch, tables: NetTables,
 
     The shared building block: the ``lax.map`` hot loop below runs it per
     design tile, and ``core.multinet`` vmaps it across the model axis with
-    per-row partitioned devices."""
-    m = _ce_maps(design, tables, dev)
-    pf, ph, pw, _cost = parallelism_search(
-        m.pes_ce, m.ce_of_layer, m.ce_oh, fc_pair, coh_pair,
-        tables.CEIL_OW, tables.OW[:, None], pairs, backend=backend,
-        design_tile=design_tile)
+    per-row partitioned devices.  Each step runs under a named scope
+    (``ce_maps``, ``parallelism_search``, ``layer_state``,
+    ``compose_metrics``) that the profiler's trace shows on its ops."""
+    with jax.named_scope("ce_maps"):
+        m = _ce_maps(design, tables, dev)
+    with jax.named_scope("parallelism_search"):
+        pf, ph, pw, _cost = parallelism_search(
+            m.pes_ce, m.ce_of_layer, m.ce_oh, fc_pair, coh_pair,
+            tables.CEIL_OW, tables.OW[:, None], pairs, backend=backend,
+            design_tile=design_tile)
     return _evaluate_core(design, tables, dev, m, (pf, ph, pw), fm_tile_rows)
 
 

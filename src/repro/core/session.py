@@ -552,33 +552,37 @@ class Session:
         cfg = self.config
         if isinstance(designs, DesignBatch):
             from .dse.encoding import NC, validate_batch
-            try:
-                ok = validate_batch(designs, len(net), min_ces=1,
-                                    max_ces=NC)
-            except Exception as e:  # noqa: BLE001 — malformed arrays
-                raise wrap(e, EvalError.INVALID_INPUT) from e
-            if not ok.all():
-                bad = np.nonzero(~ok)[0]
-                raise EvalError(
-                    EvalError.INVALID_INPUT,
-                    f"{bad.size} invalid DesignBatch row(s), first at "
-                    f"index {int(bad[0])} (non-canonical segments or CE "
-                    f"count outside [1, {NC}])")
+            with telemetry.span("session.validate"):
+                try:
+                    ok = validate_batch(designs, len(net), min_ces=1,
+                                        max_ces=NC)
+                except Exception as e:  # noqa: BLE001 — malformed arrays
+                    raise wrap(e, EvalError.INVALID_INPUT) from e
+                if not ok.all():
+                    bad = np.nonzero(~ok)[0]
+                    raise EvalError(
+                        EvalError.INVALID_INPUT,
+                        f"{bad.size} invalid DesignBatch row(s), first at "
+                        f"index {int(bad[0])} (non-canonical segments or "
+                        f"CE count outside [1, {NC}])")
             sp.set_attr("kind", "design_batch")
             sp.set_attr("designs", designs.batch)
             self.stats.bump("batch_designs", designs.batch)
-            return self._resilient_call(lambda b: evaluate_batch(
-                designs, self.tables(net), self.device_tables(dev),
-                fm_tile_rows=cfg.fm_tile_rows, backend=b,
-                tile=cfg.tile, design_tile=cfg.design_tile, mesh=self.mesh))
-        try:
-            specs = [self._parse(d, net, inter_segment_pipelining)
-                     for d in designs]
-        except Exception as e:  # noqa: BLE001
-            raise wrap(e, EvalError.INVALID_INPUT) from e
-        if not specs:
-            raise EvalError(EvalError.INVALID_INPUT,
-                            "no designs to evaluate (empty list)")
+            with telemetry.span("session.dispatch"):
+                return self._resilient_call(lambda b: evaluate_batch(
+                    designs, self.tables(net), self.device_tables(dev),
+                    fm_tile_rows=cfg.fm_tile_rows, backend=b,
+                    tile=cfg.tile, design_tile=cfg.design_tile,
+                    mesh=self.mesh))
+        with telemetry.span("session.validate"):
+            try:
+                specs = [self._parse(d, net, inter_segment_pipelining)
+                         for d in designs]
+            except Exception as e:  # noqa: BLE001
+                raise wrap(e, EvalError.INVALID_INPUT) from e
+            if not specs:
+                raise EvalError(EvalError.INVALID_INPUT,
+                                "no designs to evaluate (empty list)")
         sp.set_attr("kind", "spec_list")
         sp.set_attr("designs", len(specs))
         self.stats.bump("batch_designs", len(specs))

@@ -15,13 +15,18 @@ observable the same way.  Three pieces, stdlib-only:
 * **exporters** — a JSONL trace file (one event per line, gated by
   ``REPRO_TELEMETRY_DIR``), a Prometheus-style text :func:`prometheus_text`
   snapshot, and the in-process :func:`snapshot` dict that
-  ``Session.observability()`` merges into its reporting.
+  ``Session.observability()`` merges into its reporting;
+* **the profiler's trace** — while a ``jax.profiler`` trace is recording,
+  every span also opens a ``jax.profiler.TraceAnnotation`` of its name,
+  enabled or not, so the same names sit on the device's clock beside the
+  programs they dispatch.
 
 Telemetry is **off by default and cheap when off**: every entry point
-checks one module-level flag and returns a shared singleton — the
-disabled path allocates nothing (``tests/test_telemetry.py`` pins this,
-``benchmarks/perf_gate.py`` gates the enabled-path overhead under 3% of
-the ``session_cached`` point).  Enable it with the env var::
+checks one module-level flag and returns a shared singleton — with no
+profiler recording, the disabled path allocates nothing
+(``tests/test_telemetry.py`` pins this, ``benchmarks/perf_gate.py`` gates
+the enabled-path overhead under 3% of the ``session_cached`` point).
+Enable it with the env var::
 
     REPRO_TELEMETRY_DIR=/tmp/traces python ...   # metrics + JSONL trace
 
@@ -31,9 +36,9 @@ metrics only).  Span catalog, metric names and the trace schema:
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import os
+import sys
 import threading
 import time
 
@@ -41,15 +46,13 @@ __all__ = [
     "TELEMETRY_DIR_ENV", "enable", "disable", "enabled", "reset",
     "span", "event", "count", "gauge", "observe",
     "snapshot", "prometheus_text", "trace_path",
-    "validate_trace_line", "read_trace", "profile",
+    "validate_trace_line", "read_trace",
     "Histogram", "DEFAULT_BUCKETS",
 ]
 
 #: trace-export directory; setting it (before import or via
 #: :func:`enable`) turns telemetry on with a JSONL sink
 TELEMETRY_DIR_ENV = "REPRO_TELEMETRY_DIR"
-#: opt-in ``jax.profiler`` deep-dive directory (see :func:`profile`)
-PROFILE_ENV = "REPRO_TELEMETRY_PROFILE"
 
 #: the one flag every instrumentation site checks first.  Plain module
 #: global (not behind a lock): reads are atomic in CPython and the
@@ -226,14 +229,55 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+#: ``jax.profiler.TraceAnnotation``, looked up once jax is imported
+_ANNOTATION = None
+
+
+def _recording():
+    """``jax.profiler.TraceAnnotation`` while a profiler trace is
+    recording, else None.  Looks for jax only in ``sys.modules``: this
+    module never imports it."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _ANNOTATION = getattr(profiler, "TraceAnnotation", None)
+        if _ANNOTATION is None:
+            return None
+    return _ANNOTATION if _ANNOTATION.is_enabled() else None
+
+
+class _AnnotationSpan:
+    """A span that only marks the profiler's trace: the profiler is
+    recording while telemetry is off, so nothing reaches the registry or
+    the JSONL sink."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann):
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+    def set_attr(self, name, value):
+        pass
+
+    def add_event(self, name, **attrs):
+        pass
+
 
 class Span:
     """One timed unit of work.  Use via :func:`span`."""
 
     __slots__ = ("name", "attrs", "span_id", "parent_id", "trace_id",
-                 "events", "t_wall", "_t0", "dur_s")
+                 "events", "t_wall", "_t0", "dur_s", "_ann")
 
-    def __init__(self, name: str, attrs: dict | None):
+    def __init__(self, name: str, attrs: dict | None, ann=None):
         self.name = name
         self.attrs = dict(attrs) if attrs else {}
         self.span_id = _new_id()
@@ -243,6 +287,7 @@ class Span:
         self.t_wall = 0.0
         self._t0 = 0.0
         self.dur_s = 0.0
+        self._ann = ann
 
     def set_attr(self, name: str, value) -> None:
         self.attrs[name] = value
@@ -260,12 +305,16 @@ class Span:
         else:
             self.trace_id = self.span_id
         st.append(self)
+        if self._ann is not None:
+            self._ann.__enter__()
         self.t_wall = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.dur_s = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         st = _stack()
         if st and st[-1] is self:
             st.pop()
@@ -284,12 +333,15 @@ class Span:
 
 
 def span(name: str, attrs: dict | None = None):
-    """A context manager timing one named unit of work.  Returns the
-    shared no-op singleton while telemetry is disabled — zero allocation
-    on the disabled path."""
+    """A context manager timing one named unit of work.  While a
+    ``jax.profiler`` trace is recording it also marks that trace with
+    ``name``, whether telemetry is enabled or not.  Returns the shared
+    no-op singleton while telemetry is disabled and no profiler records —
+    zero allocation on that path."""
+    ann = _recording()
     if not _ENABLED:
-        return _NOOP
-    return Span(name, attrs)
+        return _NOOP if ann is None else _AnnotationSpan(ann(name))
+    return Span(name, attrs, None if ann is None else ann(name))
 
 
 def current_span():
@@ -504,25 +556,3 @@ def reset() -> None:
 if os.environ.get(TELEMETRY_DIR_ENV):
     enable(os.environ[TELEMETRY_DIR_ENV])
 
-
-# --------------------------------------------------------------------------
-# opt-in deep dive: jax.profiler
-# --------------------------------------------------------------------------
-@contextlib.contextmanager
-def profile(directory: str | None = None):
-    """Wrap a block in ``jax.profiler.trace`` (TensorBoard-readable)
-    when a directory is given or ``REPRO_TELEMETRY_PROFILE`` is set;
-    otherwise a no-op.  Import failures degrade to a no-op too — the
-    telemetry layer itself stays dependency-free."""
-    directory = directory or os.environ.get(PROFILE_ENV) or None
-    if not directory:
-        yield
-        return
-    try:
-        import jax
-        ctx = jax.profiler.trace(directory)
-    except Exception:  # noqa: BLE001 — profiler unavailable: stay silent
-        yield
-        return
-    with span("telemetry.profile", {"dir": directory}), ctx:
-        yield

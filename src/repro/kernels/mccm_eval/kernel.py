@@ -17,7 +17,9 @@ default ``design_tile = 16`` that is ≈ 8 MiB — comfortably under a
 Raise ``design_tile`` on parts with more VMEM.
 
 On CPU the kernels run under ``interpret=True`` (same code path, jnp
-semantics); ``core.batch_eval`` selects the backend.
+semantics); ``core.batch_eval`` selects the backend.  The compiled
+program and the profiler's trace name them ``mccm_latency`` and
+``mccm_parallelism_search``.
 """
 from __future__ import annotations
 
@@ -68,6 +70,7 @@ def mccm_latency_call(dims, par, *, design_blk: int = 512,
             jax.ShapeDtypeStruct((nb * design_blk, L), jnp.float32),
         ],
         interpret=interpret,
+        name="mccm_latency",
     )(dims, par)
     return tot[:B], cyc[:B]
 
@@ -159,6 +162,7 @@ def parallelism_search_call(pes_ce, ce_oh, fc_pair, coh_pair, ow,
         out_shape=[jax.ShapeDtypeStruct((nb * design_tile, NC), jnp.float32)
                    ] * 4,
         interpret=interpret,
+        name="mccm_parallelism_search",
     )(pes_ce.astype(jnp.float32), ce_oh.astype(jnp.float32),
       fc_pair.astype(jnp.float32), coh_pair.astype(jnp.float32),
       ow.astype(jnp.float32), row(cand), row(pair_prod), row(pair_pf),

@@ -127,6 +127,13 @@ def test_evaluate_jit_pallas_compiles(one_chip):
         tile=TILE, fm_tile_rows=2, pes_hint_static=PES_HINT,
         design_tile=DESIGN_TILE).compile()
     _check(compiled)
+    # the kernel carries its own name, and the evaluator's stages theirs,
+    # into the program the chip's profiler reports
+    text = compiled.as_text()
+    assert "mccm_parallelism_search" in text
+    for scope in ("ce_maps", "parallelism_search", "layer_state",
+                  "compose_metrics"):
+        assert f"/{scope}/" in text, scope
 
 
 def test_sharded_evaluator_compiles_on_four_chips(topo):

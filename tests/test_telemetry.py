@@ -14,7 +14,10 @@ Contracts pinned here (docs/observability.md):
   schema-valid;
 * ``Session.explain`` reproduces ``benchmarks/fig6_fig7_breakdown.py``'s
   formulas bit-for-bit (same Metrics in, same numbers out);
-* injected backend faults surface as resilience events in the trace.
+* injected backend faults surface as resilience events in the trace;
+* while a ``jax.profiler`` trace records, every span marks it by name,
+  with telemetry on or off, and ``Session.evaluate`` nests its
+  ``session.validate`` and ``session.dispatch`` spans there.
 """
 from __future__ import annotations
 
@@ -27,7 +30,7 @@ from faults import CountingHook, inject_fault
 from repro import telemetry
 from repro.api import Session
 from repro.cnn.registry import get_cnn
-from repro.core.telemetry import _NOOP, _REGISTRY, Histogram
+from repro.core.telemetry import _NOOP, _REGISTRY, Histogram, Span
 from repro.fpga.archs import make_arch
 from repro.fpga.boards import get_board
 
@@ -87,6 +90,95 @@ def test_disabled_mode_no_registry_growth_no_trace(disabled_telemetry,
     snap = telemetry.snapshot()
     assert snap["enabled"] is False
     assert snap["counters"] == snap["gauges"] == snap["histograms"] == {}
+
+
+# --------------------------------------------------------------------------
+# the profiler's trace: spans reach it, enabled or not
+# --------------------------------------------------------------------------
+WINDOW = "test.window"
+
+
+def _profiled(tmp_path, work):
+    """Run ``work()`` under a ``jax.profiler`` trace (Python tracer off)
+    inside a ``WINDOW`` annotation; return its host annotations as
+    ``{name: [(start_ns, end_ns), ...]}``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path / "prof"), profiler_options=opts):
+        with jax.profiler.TraceAnnotation(WINDOW):
+            work()
+    path, = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    spans: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    return spans
+
+
+def test_annotation_only_span_while_profiling_disabled(disabled_telemetry,
+                                                       tmp_path):
+    size0 = _REGISTRY.size()
+    got = []
+
+    def work():
+        with telemetry.span("probe.outer", {"k": 1}) as sp:
+            got.append(sp)
+            sp.set_attr("x", 1)
+            sp.add_event("e")
+            with telemetry.span("probe.inner"):
+                pass
+
+    spans = _profiled(tmp_path, work)
+    assert got[0] is not _NOOP and not isinstance(got[0], Span)
+    assert telemetry.span("after") is _NOOP, \
+        "the profiler stopped: back to the shared singleton"
+    assert _REGISTRY.size() == size0 == 0
+    assert telemetry.trace_path() is None
+    (o0, o1), = spans["probe.outer"]
+    (i0, i1), = spans["probe.inner"]
+    (w0, w1), = spans[WINDOW]
+    assert w0 <= o0 <= i0 <= i1 <= o1 <= w1
+
+
+@pytest.mark.parametrize("enabled", [False, True],
+                         ids=["telemetry_off", "telemetry_on"])
+def test_session_evaluate_spans_on_the_profiler_trace(enabled, tmp_path,
+                                                      disabled_telemetry):
+    """``session.evaluate`` encloses ``session.validate`` then
+    ``session.dispatch`` on the profiler's host trace, inside the window,
+    whether telemetry records or not; when it records, the JSONL trace
+    holds the same spans."""
+    import numpy as np
+
+    from repro.core.dse import sample_mixed
+
+    net, dev = get_cnn(NET), get_board(BOARD)
+    ses = Session(dev)
+    db = sample_mixed(np.random.default_rng(0), len(net), 32)
+    ses.evaluate(db, net)                      # compile outside the trace
+    if enabled:
+        telemetry.enable(str(tmp_path / "jsonl"))
+    spans = _profiled(tmp_path, lambda: {
+        k: np.asarray(v) for k, v in ses.evaluate(db, net).items()})
+    (w0, w1), = spans[WINDOW]
+    (e0, e1), = spans["session.evaluate"]
+    (v0, v1), = spans["session.validate"]
+    (d0, d1), = spans["session.dispatch"]
+    assert w0 <= e0 <= v0 <= v1 <= d0 <= d1 <= e1 <= w1
+    if enabled:
+        names = [l["name"] for l in
+                 telemetry.read_trace(telemetry.trace_path())]
+        assert names == ["session.validate", "session.dispatch",
+                         "session.evaluate"]
 
 
 # --------------------------------------------------------------------------
