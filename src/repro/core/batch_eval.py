@@ -280,6 +280,19 @@ def _seg_max(x, onehot):
     return big.max(axis=1)
 
 
+def _of_layer(x, seg_of_layer):
+    """``x[b, seg_of_layer[b, l]]`` for a per-segment ``x`` (B, NS).
+
+    A select chain over the static segment axis, not a gather: on a TPU a
+    (B, L) point gather costs far more than NS compares and selects, and a
+    select moves each value as it is, so every dtype comes out bit for bit
+    as ``take_along_axis`` gives it.  Indices must lie in [0, NS)."""
+    out = jnp.broadcast_to(x[:, :1], seg_of_layer.shape)
+    for s in range(1, x.shape[1]):
+        out = jnp.where(seg_of_layer == s, x[:, s:s + 1], out)
+    return out
+
+
 def seg_scan_max(vals, start_flags, reverse=False):
     """Running max within groups delimited by start_flags (B, L).
 
@@ -340,18 +353,15 @@ def _ce_maps(design: DesignBatch, t: NetTables, dev: DeviceTables) -> _CEMaps:
     valid_layer = valid_b.astype(jnp.float32) * t.valid[None, :]
     onehot = _seg_onehot(seg_of_layer, valid_layer)     # (B, max_L, NS)
 
-    idx_in_seg = layer_ix[None, :] - jnp.take_along_axis(
-        seg_start, seg_of_layer, axis=1)
-    nce_of_layer = jnp.take_along_axis(design.seg_nce, seg_of_layer, axis=1)
-    pipe_bool = (jnp.take_along_axis(
-        design.seg_pipe.astype(jnp.int32), seg_of_layer, axis=1) > 0) \
-        & valid_b
+    idx_in_seg = layer_ix[None, :] - _of_layer(seg_start, seg_of_layer)
+    nce_of_layer = _of_layer(design.seg_nce, seg_of_layer)
+    pipe_bool = _of_layer(design.seg_pipe, seg_of_layer) & valid_b
     slot_of_layer = idx_in_seg % jnp.maximum(nce_of_layer, 1)
     round_of_layer = idx_in_seg // jnp.maximum(nce_of_layer, 1)
 
     ce_base = jnp.cumsum(design.seg_nce * seg_valid, axis=-1) \
         - design.seg_nce * seg_valid
-    ce_of_layer = jnp.take_along_axis(ce_base, seg_of_layer, axis=1) \
+    ce_of_layer = _of_layer(ce_base, seg_of_layer) \
         + slot_of_layer                            # (B, max_L)
     # overflowing CEs (non-canonical rows) and padded layers map to a zero
     # one-hot row; clip keeps the ref path's gathers in bounds
@@ -534,9 +544,9 @@ def layer_state(design: DesignBatch, t: NetTables, dev: DeviceTables,
     ce_desire = jnp.einsum("bl,blc->bc", ce_desire_l, ce_oh,
                            precision=EXACT)
     seg_of_ce_desire = _seg_sum(ce_desire_l, onehot)     # (B, NS)
-    alloc_of_layer = jnp.take_along_axis(alloc, seg_of_layer, axis=1)
-    segdes_of_layer = jnp.take_along_axis(
-        jnp.maximum(seg_of_ce_desire, 1.0), seg_of_layer, axis=1)
+    alloc_of_layer = _of_layer(alloc, seg_of_layer)
+    segdes_of_layer = _of_layer(jnp.maximum(seg_of_ce_desire, 1.0),
+                                seg_of_layer)
     cedes_of_layer = jnp.einsum("bc,blc->bl", ce_desire, ce_oh,
                                 precision=EXACT)
     ce_buf_of_layer = jnp.floor(
@@ -544,8 +554,7 @@ def layer_state(design: DesignBatch, t: NetTables, dev: DeviceTables,
 
     # weights resident (Eq. 5 regime): alloc covers the Eq. 5 requirement
     resident_seg = (alloc >= desire_pipe) & is_pipe_seg
-    resident_l = jnp.take_along_axis(
-        resident_seg.astype(jnp.int32), seg_of_layer, axis=1) > 0
+    resident_l = _of_layer(resident_seg, seg_of_layer)
 
     # n_tiles per layer: max OH over the layers of the same (seg, round).
     # Rounds are contiguous layer runs, so the group max is the combine of
@@ -553,7 +562,7 @@ def layer_state(design: DesignBatch, t: NetTables, dev: DeviceTables,
     # scatter map needed.
     is_round_start = slot_of_layer == 0
     is_round_last = (slot_of_layer == nce_of_layer - 1) | \
-        (idx_in_seg == jnp.take_along_axis(seg_len, seg_of_layer, axis=1) - 1)
+        (idx_in_seg == _of_layer(seg_len, seg_of_layer) - 1)
     OH_b = jnp.broadcast_to(OH[None], (B, max_L))
     n_tiles_l = jnp.maximum(
         jnp.maximum(seg_scan_max(OH_b, is_round_start),
@@ -588,8 +597,8 @@ def layer_state(design: DesignBatch, t: NetTables, dev: DeviceTables,
     prev_on = jnp.concatenate(
         [jnp.zeros((B, 1), bool), next_on[:, :-1]], axis=1)
     is_seg_start = idx_in_seg == 0
-    prev_boundary_onchip = jnp.take_along_axis(
-        inter_onchip, jnp.maximum(seg_of_layer - 1, 0), axis=1) \
+    prev_boundary_onchip = _of_layer(
+        inter_onchip, jnp.maximum(seg_of_layer - 1, 0)) \
         & (seg_of_layer > 0)
     ifm_onchip = jnp.where(is_seg_start, prev_boundary_onchip, prev_on)
 
@@ -653,7 +662,7 @@ def compose_metrics(design: DesignBatch, t: NetTables, dev: DeviceTables,
     single_l = (1.0 - pipe_l) * valid_f
     is_round_start = slot_of_layer == 0
     is_round_last = (slot_of_layer == nce_of_layer - 1) | \
-        (idx_in_seg == jnp.take_along_axis(seg_len, seg_of_layer, axis=1) - 1)
+        (idx_in_seg == _of_layer(seg_len, seg_of_layer) - 1)
     last_of_seg = jnp.clip(seg_end - 1, 0, t.L - 1)      # (B, NS)
     OFM = jnp.asarray(t.OFM)
     IFM = jnp.asarray(t.IFM)

@@ -162,3 +162,45 @@ def test_evaluate_specs_tail_padding_exact():
     for k in whole:
         np.testing.assert_array_equal(whole[k], ragged[k], err_msg=k)
         assert len(ragged[k]) == 37
+
+
+def _per_segment(dtype, rng, B):
+    from repro.core.batch_eval import NS
+    if dtype == "int32":
+        return rng.integers(-2**31, 2**31 - 1, (B, NS), dtype=np.int32)
+    if dtype == "bool":
+        return rng.random((B, NS)) < 0.5
+    x = (rng.standard_normal((B, NS)) * 1e30).astype(np.float32)
+    special = np.array([np.inf, -np.inf, -0.0, 0.0, 3.4e38, -3.4e38,
+                        1e-45, np.nan], np.float32)
+    x.flat[:special.size * 3] = np.tile(special, 3)
+    return x
+
+
+@pytest.mark.parametrize("shifted", [False, True], ids=["seg", "seg-1"])
+@pytest.mark.parametrize("dtype", ["int32", "float32", "bool"])
+def test_of_layer_equals_take_along_axis_bitwise(dtype, shifted):
+    """The select chain reads each layer's segment entry bit for bit as
+    ``take_along_axis`` does, eagerly and under jit, for every dtype the
+    evaluator feeds it, at indices 0 and NS - 1 and at the shifted
+    ``max(seg - 1, 0)`` of the boundary lookup."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.batch_eval import NS, _of_layer
+
+    rng = np.random.default_rng(5)
+    B, L = 24, 160
+    x = _per_segment(dtype, rng, B)
+    seg = np.sort(rng.integers(0, NS, (B, L)), axis=1).astype(np.int32)
+    seg[0], seg[1] = 0, NS - 1
+    seg[2] = np.arange(L) % NS
+    idx = np.maximum(seg - 1, 0) if shifted else seg
+    assert idx.min() == 0 and idx.max() == (NS - 2 if shifted else NS - 1)
+
+    want = np.asarray(jnp.take_along_axis(jnp.asarray(x), jnp.asarray(idx),
+                                          axis=1))
+    for fn in (_of_layer, jax.jit(_of_layer)):
+        got = np.asarray(fn(jnp.asarray(x), jnp.asarray(idx)))
+        assert got.dtype == want.dtype and got.shape == (B, L)
+        np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))

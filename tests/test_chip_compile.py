@@ -15,6 +15,7 @@ compiled for a described chip can be written to it but never read back.
 from __future__ import annotations
 
 import os
+import re
 from functools import partial
 
 import numpy as np
@@ -134,6 +135,11 @@ def test_evaluate_jit_pallas_compiles(one_chip):
     for scope in ("ce_maps", "parallelism_search", "layer_state",
                   "compose_metrics"):
         assert f"/{scope}/" in text, scope
+    # each layer reads its segment's entries through a select chain: a
+    # (tile, max_L) point gather costs a v5e ~170 us per tile apiece
+    layer_gathers = re.findall(
+        rf"\w+\[{TILE},{MAX_L}\]\S*\s+gather\(", text)
+    assert not layer_gathers, layer_gathers
 
 
 def test_sharded_evaluator_compiles_on_four_chips(topo):
