@@ -31,6 +31,7 @@ compiles.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 
 import numpy as np
 
@@ -256,6 +257,23 @@ def validate_batch_jax(batch: DesignBatch, n_layers, *,
     total = (seg_nce * active).sum(1)
     ok &= (total >= min_ces) & (total <= min(max_ces, NC))
     return ok
+
+
+@partial(jax.jit, static_argnames=("min_ces", "max_ces"))
+def count_invalid_jit(batch: DesignBatch, n_layers, *,
+                      min_ces: int = 1, max_ces: int = NC) -> jnp.ndarray:
+    """``[n_invalid, first_invalid_index]`` (int32, shape (2,)) of
+    :func:`validate_batch_jax` — the check ``Session.evaluate`` runs on the
+    device before dispatch, pulling 8 bytes instead of the batch.
+
+    ``n_layers`` is traced, so one compile serves every CNN at a batch
+    shape; the first index is 0 when every row is valid.
+    """
+    bad = ~validate_batch_jax(batch, n_layers, min_ces=min_ces,
+                              max_ces=max_ces)
+    first = jnp.argmax(bad) if bad.size else 0   # argmax needs a row
+    return jnp.stack([bad.sum(dtype=jnp.int32),
+                      jnp.asarray(first, jnp.int32)])
 
 
 def repair_batch_jax(batch: DesignBatch, n_layers, *,

@@ -47,7 +47,7 @@ from .cache import (DEFAULT_MAX_TABLES, TABLES_ENV, BoundedLRU,
 from .coalesce import ArrivalEstimator, plan_megabatch
 from .device import DeviceSpec
 from .dse.driver import DEFAULT_OBJECTIVES
-from .dse.encoding import DesignBatch
+from .dse.encoding import NC, DesignBatch, count_invalid_jit
 from .evaluator import _evaluate_design, build_design
 from .notation import AcceleratorSpec, parse
 from .resilience import (CircuitBreaker, EvalError, classify,
@@ -551,19 +551,20 @@ class Session:
             return m
         cfg = self.config
         if isinstance(designs, DesignBatch):
-            from .dse.encoding import NC, validate_batch
             with telemetry.span("session.validate"):
+                # checked on the device: two scalars come back, not the batch
                 try:
-                    ok = validate_batch(designs, len(net), min_ces=1,
-                                        max_ces=NC)
+                    n_bad, first = (int(v) for v in np.asarray(
+                        count_invalid_jit(designs, len(net), min_ces=1,
+                                          max_ces=NC)))
                 except Exception as e:  # noqa: BLE001 — malformed arrays
                     raise wrap(e, EvalError.INVALID_INPUT) from e
-                if not ok.all():
-                    bad = np.nonzero(~ok)[0]
+                if n_bad:
+                    telemetry.count("session.rejected_rows", n_bad)
                     raise EvalError(
                         EvalError.INVALID_INPUT,
-                        f"{bad.size} invalid DesignBatch row(s), first at "
-                        f"index {int(bad[0])} (non-canonical segments or "
+                        f"{n_bad} invalid DesignBatch row(s), first at "
+                        f"index {first} (non-canonical segments or "
                         f"CE count outside [1, {NC}])")
             sp.set_attr("kind", "design_batch")
             sp.set_attr("designs", designs.batch)
@@ -1272,6 +1273,7 @@ class Session:
         # submodule attribute — resolve the module explicitly
         dse_search = importlib.import_module(".dse.search", __package__)
         counts = {
+            "validate_batch": count_invalid_jit._cache_size(),
             "evaluate_batch": batch_eval._evaluate_jit._cache_size(),
             "dse_step": sum(f._cache_size()
                             for f in dse_search._STEP_CACHE.values()),

@@ -29,6 +29,7 @@ V5E_HBM_BYTES = 16 * 10**9
 TILE, MAX_L, NC, K, DESIGN_TILE = 128, 160, 16, 18, 16
 PES_HINT = 2520
 EVAL_B = 4096
+SWEEP_B = 65_536
 POP = 4096
 JOINT_B = 512
 
@@ -140,6 +141,23 @@ def test_evaluate_jit_pallas_compiles(one_chip):
     layer_gathers = re.findall(
         rf"\w+\[{TILE},{MAX_L}\]\S*\s+gather\(", text)
     assert not layer_gathers, layer_gathers
+
+
+@pytest.mark.parametrize("B", [EVAL_B, SWEEP_B])
+def test_batch_check_compiles(one_chip, B):
+    """The device check ``Session.evaluate`` runs before dispatch, at the
+    evaluator's compile shape and at the sweep's batch."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.dse.encoding import count_invalid_jit
+
+    n_layers = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = count_invalid_jit.lower(_design_shapes(B, one_chip), n_layers,
+                                       min_ces=1, max_ces=NC).compile()
+    _check(compiled, kernel=False)
+    out, = jax.tree.leaves(compiled.out_info)
+    assert (out.shape, out.dtype) == ((2,), jnp.int32)
 
 
 def test_sharded_evaluator_compiles_on_four_chips(topo):
