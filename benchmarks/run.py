@@ -1,5 +1,5 @@
-"""Benchmark harness: one entry per paper table/figure + the TPU-side
-dry-run/roofline reports.  ``python -m benchmarks.run [--quick]``.
+"""Benchmark harness: one entry per paper table/figure plus the CPU
+speed gates.  ``python -m benchmarks.run [--quick]``.
 
 Prints ``name,seconds,checks`` CSV at the end; artifacts land in
 ``artifacts/bench/*.json``.
@@ -20,9 +20,8 @@ def main(argv=None) -> int:
 
     from . import (eval_speed, fig5_fig8_fronts, fig6_fig7_breakdown,
                    fig9_fig10_dse, multinet_fronts, multinet_hybrid,
-                   perf_gate,
-                   roofline_report, tab1_arch_comparison, tab4_accuracy,
-                   tab5_best_arch, tpu_model_accuracy)
+                   perf_gate, tab1_arch_comparison, tab4_accuracy,
+                   tab5_best_arch)
 
     entries = [
         ("tab1_arch_comparison", tab1_arch_comparison.run, {}),
@@ -36,8 +35,6 @@ def main(argv=None) -> int:
         ("multinet_hybrid", multinet_hybrid.run, {"quick": args.quick}),
         ("eval_speed", eval_speed.run, {}),
         ("perf_gate", perf_gate.run, {"quick": args.quick}),
-        ("roofline_report", roofline_report.run, {}),
-        ("tpu_model_accuracy", tpu_model_accuracy.run, {}),
     ]
     if args.only:
         keep = set(args.only.split(","))

@@ -1,9 +1,7 @@
 """Device abstraction under MCCM.
 
 The paper instantiates the model on FPGA boards (PEs = DSPs, on-chip = BRAM,
-off-chip = DDR).  The same record also carries the TPU instantiation used by
-``repro.tpu`` (PEs = MXU lanes, on-chip = HBM per chip, off-chip = ICI), which
-is how the cost model is hardware-adapted without changing its equations.
+off-chip = DDR).
 """
 from __future__ import annotations
 

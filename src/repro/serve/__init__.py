@@ -1,27 +1,8 @@
-"""Serving fronts: the LM generation engine and the MCCM socket service.
-
-Lazy attribute resolution keeps the two independent: importing
-``EvalServer``/``ServeClient`` (the evaluation service, docs/serving.md)
-must not pull the generation engine's model stack, and vice versa.
-"""
+"""The MCCM socket service (docs/serving.md): ``EvalServer`` answers
+NDJSON evaluation requests over TCP, ``ServeClient`` sends them."""
 from __future__ import annotations
 
-_EXPORTS = {
-    "GenerationResult": ".engine",
-    "ServeEngine": ".engine",
-    "EvalServer": ".server",
-    "jsonify": ".server",
-    "summarize_search": ".server",
-    "ServeClient": ".client",
-}
+from .client import ServeClient
+from .server import EvalServer, jsonify, summarize_search
 
-__all__ = list(_EXPORTS)
-
-
-def __getattr__(name: str):
-    mod = _EXPORTS.get(name)
-    if mod is None:
-        raise AttributeError(f"module {__name__!r} has no attribute "
-                             f"{name!r}")
-    import importlib
-    return getattr(importlib.import_module(mod, __name__), name)
+__all__ = ["EvalServer", "jsonify", "summarize_search", "ServeClient"]

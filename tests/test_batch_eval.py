@@ -10,7 +10,7 @@ from repro.core.batch_eval import (SCALAR_PARITY_RTOL, encode_specs,
 from repro.core.dse import decode_design, explore, pareto, sample_mixed
 from repro.core.evaluator import evaluate_design
 from repro.fpga.archs import ARCH_NAMES, make_arch
-from repro.fpga.boards import get_board
+from repro.fpga.boards import BOARD_NAMES, get_board
 
 METRICS = tuple(SCALAR_PARITY_RTOL)
 RTOL = SCALAR_PARITY_RTOL
@@ -22,10 +22,11 @@ def _scalar_vals(m):
             "access_bytes": m.access_bytes}
 
 
+@pytest.mark.parametrize("board", BOARD_NAMES)
 @pytest.mark.parametrize("cnn", CNN_NAMES)
-def test_matches_scalar_on_templates(cnn):
+def test_matches_scalar_on_templates(cnn, board):
     net = get_cnn(cnn)
-    dev = get_board("vcu108")
+    dev = get_board(board)
     specs = [make_arch(a, net, n) for a in ARCH_NAMES for n in (2, 5, 9, 11)]
     scalar = [evaluate_design(s, net, dev) for s in specs]
     batch = evaluate_specs(specs, net, dev)
@@ -37,9 +38,17 @@ def test_matches_scalar_on_templates(cnn):
                 err_msg=f"{cnn} {specs[i].name} {k}")
 
 
-def test_matches_scalar_on_random_mixed_designs():
+# ROADMAP D12: on zcu102 the batch path's access_bytes for design 14 is
+# 17.7% below evaluate_design's
+_D12 = pytest.mark.xfail(strict=True, reason="ROADMAP D12: batch access_bytes "
+                         "differs from evaluate_design on zcu102")
+
+
+@pytest.mark.parametrize("board", [
+    pytest.param(b, marks=_D12) if b == "zcu102" else b for b in BOARD_NAMES])
+def test_matches_scalar_on_random_mixed_designs(board):
     net = get_cnn("resnet50")
-    dev = get_board("zc706")
+    dev = get_board(board)
     rng = np.random.default_rng(7)
     db = sample_mixed(rng, len(net), 24)
     batch = {k: np.asarray(v) for k, v in
@@ -78,13 +87,14 @@ def test_explore_speed_and_consistency():
     assert np.all(m["throughput_ips"] * m["latency_s"] >= 0.99)
 
 
-def test_fused_path_backends_bit_identical():
+@pytest.mark.parametrize("board", BOARD_NAMES)
+def test_fused_path_backends_bit_identical(board):
     """The Pallas kernel (interpret mode — what TPU runs, on CPU) and the
     pure-jnp ref produce bit-identical metrics through evaluate_batch."""
     from repro.core.batch_eval import evaluate_batch, make_tables
 
     net = get_cnn("xception")
-    dev = get_board("zc706")
+    dev = get_board(board)
     rng = np.random.default_rng(11)
     db = sample_mixed(rng, len(net), 48)
     tables = make_tables(net)
@@ -95,10 +105,11 @@ def test_fused_path_backends_bit_identical():
             np.asarray(ref[k]), np.asarray(pal[k]), err_msg=k)
 
 
-def test_matches_scalar_through_pallas_interpret():
+@pytest.mark.parametrize("board", BOARD_NAMES)
+def test_matches_scalar_through_pallas_interpret(board):
     """Scalar parity holds through the fused kernel path itself."""
     net = get_cnn("mobilenetv2")
-    dev = get_board("vcu108")
+    dev = get_board(board)
     specs = [make_arch(a, net, n) for a in ARCH_NAMES for n in (2, 9)]
     batch = evaluate_specs(specs, net, dev, backend="pallas_interpret")
     for i, s in enumerate(specs):
@@ -117,7 +128,6 @@ def test_one_compile_serves_all_cnns_and_boards():
 
     from repro.core import batch_eval
     from repro.core.batch_eval import evaluate_batch, make_tables
-    from repro.fpga.boards import BOARD_NAMES
 
     jax.clear_caches()
     assert batch_eval._evaluate_jit._cache_size() == 0

@@ -8,35 +8,8 @@ import pytest
 
 from repro.kernels.conv_ce.ops import conv_ce, grid_size, predicted_cycles
 from repro.kernels.conv_ce.ref import conv_ref
-from repro.kernels.flash_attn.ops import flash_attention
-from repro.kernels.flash_attn.ref import attention_ref
 from repro.kernels.mccm_eval.ops import mccm_latency
 from repro.kernels.mccm_eval.ref import mccm_latency_ref
-
-
-# ------------------------------------------------------------- flash_attn
-@pytest.mark.parametrize("B,Sq,Sk,H,Hkv,D,causal,window,dtype", [
-    (2, 128, 128, 4, 2, 64, True, None, jnp.float32),
-    (1, 200, 200, 2, 2, 32, True, 64, jnp.float32),
-    (2, 64, 256, 4, 4, 64, False, None, jnp.float32),
-    (1, 1, 300, 4, 2, 64, False, None, jnp.float32),      # decode-like
-    (2, 96, 96, 2, 1, 128, True, None, jnp.bfloat16),
-])
-def test_flash_attention_vs_ref(B, Sq, Sk, H, Hkv, D, causal, window, dtype):
-    q = jax.random.normal(jax.random.key(0), (B, Sq, H, D), dtype)
-    k = jax.random.normal(jax.random.key(1), (B, Sk, Hkv, D), dtype)
-    v = jax.random.normal(jax.random.key(2), (B, Sk, Hkv, D), dtype)
-    out = flash_attention(q, k, v, causal=causal, window=window,
-                          q_blk=64, kv_blk=64)
-    kk = jnp.repeat(k, H // Hkv, 2)
-    vv = jnp.repeat(v, H // Hkv, 2)
-    ref = attention_ref(q.transpose(0, 2, 1, 3), kk.transpose(0, 2, 1, 3),
-                        vv.transpose(0, 2, 1, 3), causal=causal,
-                        window=window).transpose(0, 2, 1, 3)
-    tol = 5e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=tol, atol=tol)
 
 
 # ------------------------------------------------------------- conv_ce
