@@ -293,6 +293,17 @@ def _of_layer(x, seg_of_layer):
     return out
 
 
+def _of_ce(x, m: _CEMaps):
+    """``x[b, ce_of_layer[b, l]]`` for a per-CE ``x`` (B, NC), 0 where the
+    layer holds no CE (``m.ce_live`` false: a padded layer, or a CE index
+    past NC in a non-canonical row).
+
+    The select chain of ``_of_layer`` over the static CE axis: bit for bit
+    what ``einsum("bc,blc->bl", x, m.ce_oh)`` gives for finite ``x``, which
+    a v5e runs as a multiply and a 16-lane reduce over a (B, L, NC) block."""
+    return jnp.where(m.ce_live, _of_layer(x, m.ce_of_layer), 0.0)
+
+
 def seg_scan_max(vals, start_flags, reverse=False):
     """Running max within groups delimited by start_flags (B, L).
 
@@ -330,6 +341,7 @@ class _CEMaps(NamedTuple):
     ce_oh: jnp.ndarray
     pes_ce: jnp.ndarray
     ce_valid: jnp.ndarray
+    ce_live: jnp.ndarray        # (B, max_L) bool: ce_oh's row is not zero
 
 
 def _ce_maps(design: DesignBatch, t: NetTables, dev: DeviceTables) -> _CEMaps:
@@ -364,9 +376,11 @@ def _ce_maps(design: DesignBatch, t: NetTables, dev: DeviceTables) -> _CEMaps:
     ce_of_layer = _of_layer(ce_base, seg_of_layer) \
         + slot_of_layer                            # (B, max_L)
     # overflowing CEs (non-canonical rows) and padded layers map to a zero
-    # one-hot row; clip keeps the ref path's gathers in bounds
+    # one-hot row, and ce_live is false there; clip keeps the ref path's
+    # gathers in bounds
     ce_oh = jax.nn.one_hot(ce_of_layer, NC, dtype=jnp.float32) \
         * valid_layer[..., None]
+    ce_live = (ce_of_layer >= 0) & (ce_of_layer < NC) & (valid_layer > 0)
     ce_of_layer = jnp.clip(ce_of_layer, 0, NC - 1)
 
     # PE distribution (largest remainder over per-CE MACs)
@@ -377,7 +391,7 @@ def _ce_maps(design: DesignBatch, t: NetTables, dev: DeviceTables) -> _CEMaps:
     return _CEMaps(seg_start, seg_len, seg_valid, n_seg, seg_of_layer,
                    onehot, valid_b, idx_in_seg, nce_of_layer, pipe_bool,
                    slot_of_layer, round_of_layer, ce_base, ce_of_layer,
-                   ce_oh, pes_ce, ce_valid)
+                   ce_oh, pes_ce, ce_valid, ce_live)
 
 
 def _pair_layer_tables(t: NetTables, pairs):
@@ -446,19 +460,16 @@ def layer_state(design: DesignBatch, t: NetTables, dev: DeviceTables,
     pf_ce, ph_ce, pw_ce = par
     (seg_start, seg_len, seg_valid, n_seg, seg_of_layer, onehot, valid_b,
      idx_in_seg, nce_of_layer, pipe_bool, slot_of_layer, _round,
-     ce_base, _ce_of_layer, ce_oh, _pes, ce_valid) = m
+     ce_base, _ce_of_layer, ce_oh, _pes, ce_valid, _live) = m
     valid_f = valid_b.astype(jnp.float32)
     seg_end = design.seg_end
 
     # ---- per-layer compute cycles & utilization --------------------------
     macs = jnp.asarray(t.MACS)
     ckk = jnp.asarray(t.CKK)
-    pf_l = jnp.where(valid_b, jnp.einsum("bc,blc->bl", pf_ce, ce_oh,
-                                         precision=EXACT), 1.0)
-    ph_l = jnp.where(valid_b, jnp.einsum("bc,blc->bl", ph_ce, ce_oh,
-                                         precision=EXACT), 1.0)
-    pw_l = jnp.where(valid_b, jnp.einsum("bc,blc->bl", pw_ce, ce_oh,
-                                         precision=EXACT), 1.0)
+    pf_l = jnp.where(valid_b, _of_ce(pf_ce, m), 1.0)
+    ph_l = jnp.where(valid_b, _of_ce(ph_ce, m), 1.0)
+    pw_l = jnp.where(valid_b, _of_ce(pw_ce, m), 1.0)
     F = jnp.asarray(t.F)
     OH = jnp.asarray(t.OH)
     OW = jnp.asarray(t.OW)
@@ -547,8 +558,7 @@ def layer_state(design: DesignBatch, t: NetTables, dev: DeviceTables,
     alloc_of_layer = _of_layer(alloc, seg_of_layer)
     segdes_of_layer = _of_layer(jnp.maximum(seg_of_ce_desire, 1.0),
                                 seg_of_layer)
-    cedes_of_layer = jnp.einsum("bc,blc->bl", ce_desire, ce_oh,
-                                precision=EXACT)
+    cedes_of_layer = _of_ce(ce_desire, m)
     ce_buf_of_layer = jnp.floor(
         alloc_of_layer * cedes_of_layer / segdes_of_layer)
 
@@ -655,7 +665,7 @@ def compose_metrics(design: DesignBatch, t: NetTables, dev: DeviceTables,
     wb = dev.wordbytes
     (seg_start, seg_len, seg_valid, n_seg, seg_of_layer, onehot, valid_b,
      idx_in_seg, nce_of_layer, pipe_bool, slot_of_layer, _round,
-     ce_base, _ce_of_layer, ce_oh, _pes, ce_valid) = m
+     ce_base, _ce_of_layer, ce_oh, _pes, ce_valid, _live) = m
     valid_f = valid_b.astype(jnp.float32)
     seg_end = design.seg_end
     pipe_l = pipe_bool.astype(jnp.float32)

@@ -30,13 +30,13 @@ import jax.numpy as jnp
 
 from ..core.batch_eval import (DEFAULT_TILE, NEG, DesignBatch, DeviceSpec,
                                DeviceTables, LayerState, NetTables, _bucket,
-                               _ce_maps, _pad_rows, _pair_layer_tables,
-                               _seg_max, _seg_sum, compose_metrics,
-                               layer_state, make_device_tables, pes_hint)
+                               _ce_maps, _of_ce, _pad_rows,
+                               _pair_layer_tables, _seg_max, _seg_sum,
+                               compose_metrics, layer_state,
+                               make_device_tables, pes_hint)
 from ..core.dse.encoding import NS, encode_specs
 from ..kernels.mccm_eval import pair_tables, parallelism_search
 from ..kernels.mccm_eval import resolve_backend as resolve_eval_backend
-from ..kernels.mccm_eval.ref import EXACT
 from ..kernels.schedule_score import NCAND
 from ..kernels.schedule_score.ops import score_plane_dispatch
 
@@ -142,9 +142,7 @@ def schedule_block(design: DesignBatch, t: NetTables, dev: DeviceTables,
         budget_bytes=take(plane["budget_bytes"]),
         lat_ref_l=lat_ref_l, lat_coarse_l=lat_coarse_l,
         acc_ref_l=acc_ref_l, acc_coarse_l=acc_coarse_l,
-        pf_l=jnp.einsum("bc,blc->bl", pf, m.ce_oh, precision=EXACT),
-        ph_l=jnp.einsum("bc,blc->bl", ph, m.ce_oh, precision=EXACT),
-        pw_l=jnp.einsum("bc,blc->bl", pw, m.ce_oh, precision=EXACT),
+        pf_l=_of_ce(pf, m), ph_l=_of_ce(ph, m), pw_l=_of_ce(pw, m),
         ce_of_layer=m.ce_of_layer, seg_of_layer=m.seg_of_layer,
         pipe_l=pipe,
         valid_l=jnp.broadcast_to(valid, (design.batch, t.max_L)),

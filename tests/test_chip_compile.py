@@ -141,6 +141,10 @@ def test_evaluate_jit_pallas_compiles(one_chip):
     layer_gathers = re.findall(
         rf"\w+\[{TILE},{MAX_L}\]\S*\s+gather\(", text)
     assert not layer_gathers, layer_gathers
+    # and its CE's ⟨pf, ph, pw⟩ and buffer desire through another: a v5e
+    # runs the one-hot contraction as a multiply and a 16-lane reduce over
+    # a (tile, max_L, NC) block, ~37 us per tile for the four of them
+    assert "bc,blc->bl" not in text
 
 
 @pytest.mark.parametrize("B", [EVAL_B, SWEEP_B])
